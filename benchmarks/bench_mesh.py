@@ -26,6 +26,13 @@ The parent then assembles ``BENCH_serving.json::mesh``:
 The largest worker records a telemetry trace (under
 ``benchmarks/results/``) whose ``place`` events carry the full mesh
 placement; the parent replays it through the offline checker.
+
+CPU only.  ``--xla_force_host_platform_device_count`` fakes devices on the
+CPU backend and means nothing on a TPU host: there every worker sees the
+host's real chips, and since the parent never initialises a backend the
+workers do not fight it for them.  Its timings are CPU timings, not device
+numbers; ``chip_smoke.py --four-chips`` is what runs the mesh path on
+chips, in one process.
 """
 from __future__ import annotations
 

@@ -15,6 +15,7 @@ bitvectors to sharding preferences (the data-aware hook, DESIGN.md §2).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -279,3 +280,15 @@ def placement_hint(props: VBProps) -> dict:
 def shardings_of(spec_tree, mesh: Mesh):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
+
+
+def init_sharded_params(cfg: ModelConfig, key, mesh: Mesh):
+    """``init_params`` with every leaf created directly in its
+    ``param_specs`` sharding on ``mesh`` (one jitted init with
+    ``out_shardings``): no device ever holds a whole sharded leaf, and the
+    values do not depend on the mesh."""
+    from ..models.model import init_params    # models imports this package
+    init = partial(init_params, cfg)
+    shardings = shardings_of(
+        param_specs(cfg, jax.eval_shape(init, key), mesh), mesh)
+    return jax.jit(init, out_shardings=shardings)(key)

@@ -11,10 +11,13 @@ straight to HBM.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 WORD_BITS = 32
 
@@ -40,7 +43,7 @@ def _unpack_kernel(p_ref, o_ref, *, n_bits: int):
 
 @functools.partial(jax.jit, static_argnames=("n_bits", "block_words", "interpret"))
 def pack_tiles(x_words: jax.Array, n_bits: int, block_words: int = 256,
-               interpret: bool = True) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     """x_words: uint32[n_words, 32] → planes uint32[n_bits, n_words]."""
     n_words = x_words.shape[0]
     assert n_words % block_words == 0
@@ -50,13 +53,13 @@ def pack_tiles(x_words: jax.Array, n_bits: int, block_words: int = 256,
         grid=(n_words // block_words,),
         in_specs=[pl.BlockSpec((block_words, WORD_BITS), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((n_bits, block_words), lambda i: (0, i)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_words)
 
 
 @functools.partial(jax.jit, static_argnames=("n_bits", "block_words", "interpret"))
 def unpack_tiles(planes: jax.Array, n_bits: int, block_words: int = 256,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """planes uint32[n_bits, n_words] → x uint32[n_words, 32]."""
     n_words = planes.shape[1]
     assert n_words % block_words == 0
@@ -66,5 +69,5 @@ def unpack_tiles(planes: jax.Array, n_bits: int, block_words: int = 256,
         grid=(n_words // block_words,),
         in_specs=[pl.BlockSpec((n_bits, block_words), lambda i: (0, i))],
         out_specs=pl.BlockSpec((block_words, WORD_BITS), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(planes)

@@ -1,6 +1,8 @@
 """jit'd public wrappers for the transposition-unit kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -9,7 +11,7 @@ from .kernel import pack_tiles, unpack_tiles
 
 
 def to_bitplanes(x: jax.Array, n_bits: int, signed: bool = True,
-                 block_words: int = 256, interpret: bool = True
+                 block_words: int = 256, interpret: Optional[bool] = None
                  ) -> BitPlaneArray:
     """Horizontal int array → vertical bit-plane layout (Pallas path)."""
     n_elems = x.shape[0]
@@ -24,7 +26,7 @@ def to_bitplanes(x: jax.Array, n_bits: int, signed: bool = True,
 
 
 def from_bitplanes(bp: BitPlaneArray, out_dtype=jnp.int32,
-                   block_words: int = 256, interpret: bool = True
+                   block_words: int = 256, interpret: Optional[bool] = None
                    ) -> jax.Array:
     """Vertical bit-plane layout → horizontal ints (sign-extended)."""
     n_bits, nw = bp.planes.shape

@@ -18,10 +18,13 @@ shapes default to MXU-aligned 128 multiples.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 
 def _bsmm_kernel(x_ref, w_ref, o_ref, *, n_bits: int):
@@ -47,7 +50,7 @@ def _bsmm_kernel(x_ref, w_ref, o_ref, *, n_bits: int):
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def bsmm_raw(x: jax.Array, w_planes: jax.Array, bm: int = 128, bn: int = 128,
-             bk: int = 128, interpret: bool = True) -> jax.Array:
+             bk: int = 128, interpret: Optional[bool] = None) -> jax.Array:
     """Σ_b 2^b (x @ w_planes[b]) — raw biased accumulation (int32[M, N])."""
     M, K = x.shape
     n_bits, K2, N = w_planes.shape
@@ -63,5 +66,5 @@ def bsmm_raw(x: jax.Array, w_planes: jax.Array, bm: int = 128, bn: int = 128,
             pl.BlockSpec((n_bits, bk, bn), lambda i, j, k: (0, k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_planes)

@@ -9,7 +9,7 @@ decode hillclimb.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +40,8 @@ def quantize_activations(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 def bitserial_matmul(x_i8: jax.Array, x_scale: jax.Array,
                      w_planes: jax.Array, w_scale: jax.Array,
-                     interpret: bool = True, bm: int = 128, bn: int = 128,
-                     bk: int = 128) -> jax.Array:
+                     interpret: Optional[bool] = None, bm: int = 128,
+                     bn: int = 128, bk: int = 128) -> jax.Array:
     """Full quantized matmul: dequantized f32 [M, N]."""
     n_bits = w_planes.shape[0]
     zero = 1 << (n_bits - 1)
@@ -74,7 +74,8 @@ class QuantizedLinear:
     def from_dense(cls, w: jax.Array, n_bits: int = 8) -> "QuantizedLinear":
         return cls(*quantize_weights(w, n_bits))
 
-    def __call__(self, x: jax.Array, interpret: bool = True) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 interpret: Optional[bool] = None) -> jax.Array:
         shape = x.shape
         x2 = x.reshape(-1, shape[-1])
         xi, xs = quantize_activations(x2)

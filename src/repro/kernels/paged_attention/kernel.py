@@ -15,11 +15,14 @@ grid = (max_pages,), online-softmax accumulation in VMEM scratch, GQA via a
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -60,7 +63,7 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attn_one_seq(page_table: jax.Array, seq_len: jax.Array,
                        q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """q [n_kv, g, d]; k/v_pages [n_pages, ps, n_kv, d];
     page_table [max_pages] int32; seq_len [1] int32 → out [n_kv, g, d]."""
     max_pages = page_table.shape[0]
@@ -85,5 +88,5 @@ def paged_attn_one_seq(page_table: jax.Array, seq_len: jax.Array,
                 pltpu.VMEM((n_kv, g), jnp.float32),
                 pltpu.VMEM((n_kv, g, dh), jnp.float32),
             ]),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table, seq_len, q, k_pages, v_pages)

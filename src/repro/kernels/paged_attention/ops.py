@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,7 @@ from .kernel import paged_attn_one_seq
                    static_argnames=("n_kv", "interpret", "max_pages"))
 def paged_decode_attention(q: jax.Array, state: PagedKVState, layer,
                            n_kv: int, seq_ids=None, max_pages=None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """q: [batch, n_q_heads, head_dim] (one decode step; sequence ``i`` uses
     page-table row ``seq_ids[i]``, default 0..batch-1); returns
     [batch, n_q_heads, head_dim]."""

@@ -13,7 +13,7 @@ tiles instead of whole arrays.
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 
 from ...core.engine import execute
 from ...core.uprogram import UProgram
+from .. import resolve_interpret
 
 
 def make_vm_kernel(uprog: UProgram, input_names: Sequence[str],
@@ -36,7 +37,8 @@ def make_vm_kernel(uprog: UProgram, input_names: Sequence[str],
 
 def run_uprogram(uprog: UProgram, planes: Tuple[jax.Array, ...],
                  input_names: Sequence[str], out_bits: int,
-                 block_words: int = 128, interpret: bool = True) -> jax.Array:
+                 block_words: int = 128,
+                 interpret: Optional[bool] = None) -> jax.Array:
     """Execute a μProgram over packed planes [n_bits_i, n_words] each."""
     n_words = planes[0].shape[1]
     assert n_words % block_words == 0, "pad words to block multiple"
@@ -51,5 +53,5 @@ def run_uprogram(uprog: UProgram, planes: Tuple[jax.Array, ...],
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((out_bits, block_words), lambda i: (0, i)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*planes)

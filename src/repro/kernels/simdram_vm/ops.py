@@ -1,6 +1,8 @@
 """Public API: run any registered SIMDRAM operation through the Pallas VM."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from ...core.bitplane import BitPlaneArray
@@ -9,7 +11,7 @@ from .kernel import run_uprogram
 
 
 def simdram_op(name: str, *inputs: BitPlaneArray, style: str = "simdram",
-               block_words: int = 128, interpret: bool = True
+               block_words: int = 128, interpret: Optional[bool] = None
                ) -> BitPlaneArray:
     spec = OPS[name]
     n = inputs[0].n_bits

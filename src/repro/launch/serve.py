@@ -1,7 +1,10 @@
 """Serving launcher: jitted continuous-batching over the VBI-paged engine.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
         --requests 6 --max-new 24 --shared-prefix 32
+
+``--smoke`` (the default) serves the reduced CPU-test config;
+``--no-smoke`` serves the config's published widths.
 
 Default path: serve/engine.py (single jitted decode dispatch, device-side
 delayed page allocation) driven by serve/scheduler.py (admission, chunked
@@ -70,6 +73,7 @@ from ..serve.engine import PagedEngine
 from ..serve.prefix_cache import PrefixCache
 from ..serve.scheduler import Scheduler
 from ..serve.telemetry import Telemetry
+from .compile_cache import enable_compile_cache
 
 
 def serve_config(arch: str, smoke: bool = True):
@@ -88,10 +92,13 @@ def serve_config(arch: str, smoke: bool = True):
                                compute_dtype="float32", n_vis_tokens=0)
 
 
-def main(argv=None) -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=ARCH_IDS)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced CPU-test config (default); "
+                         "--no-smoke serves the published widths")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--batch-slots", type=int, default=4)
@@ -136,9 +143,9 @@ def main(argv=None) -> None:
                          "ring frames and recurrent state shard over "
                          "'model', the page table / allocator / scheduler "
                          "stay host-global; MoE stacks dispatch expert-"
-                         "parallel.  Needs that many devices "
-                         "(XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=N on CPU)")
+                         "parallel.  Needs that many devices (on the CPU "
+                         "backend only, XLA_FLAGS=--xla_force_host_"
+                         "platform_device_count=N fakes them)")
     ap.add_argument("--kv-layout", default="auto",
                     choices=("auto", "shard", "replicate"),
                     help="pool layout on a >1-device mesh: 'auto' picks by "
@@ -198,7 +205,13 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--legacy", action="store_true",
                     help="per-sequence reference path (serve/paged.py)")
+    return ap
+
+
+def main(argv=None) -> None:
+    ap = build_parser()
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.legacy and (args.traffic or args.overlap):
         ap.error("--traffic/--overlap need the jitted engine path "
                  "(drop --legacy)")
@@ -243,7 +256,12 @@ def main(argv=None) -> None:
         ap.error(f"--legacy (serve/paged.py) only supports uniform dense "
                  f"GQA stacks; {cfg.name} needs the property-typed engine "
                  f"(drop --legacy)")
-    params = init_params(cfg, jax.random.key(args.seed))
+    if mesh is not None:
+        # created in their shardings: never whole on one device
+        from ..distributed.sharding import init_sharded_params
+        params = init_sharded_params(cfg, jax.random.key(args.seed), mesh)
+    else:
+        params = init_params(cfg, jax.random.key(args.seed))
     rng = np.random.default_rng(args.seed)
     system = rng.integers(0, cfg.vocab, args.shared_prefix).tolist()
     prompts = [system + rng.integers(0, cfg.vocab, args.prompt_len).tolist()

@@ -21,6 +21,7 @@ from ..configs import ARCH_IDS, get_config, smoke_config
 from ..data.pipeline import SyntheticLMData
 from ..distributed.axes import logical_axes
 from ..distributed.sharding import batch_spec, shardings_of, state_specs
+from ..launch.compile_cache import enable_compile_cache
 from ..launch.mesh import make_host_mesh, make_production_mesh
 from ..optim.adamw import AdamWConfig
 from ..train.loop import TrainLoop
@@ -42,6 +43,7 @@ def main(argv=None) -> None:
                                                        "multipod"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = dataclasses.replace(cfg, grad_accum=1)

@@ -218,13 +218,12 @@ def batched_paged_attention(q: jax.Array, k_pages_l: jax.Array,
 def _kernel_paged_attention(q, k_pages_l, v_pages_l, page_table, seq_lens,
                             max_pages: int) -> jax.Array:
     """Same contract via the Pallas kernel (vmapped over slots); lowers for
-    real on TPU, interpret-mode everywhere else."""
+    real on TPU, interpret-mode everywhere else
+    (``kernels.resolve_interpret``)."""
     pts = page_table[:, :max_pages]
-    interpret = jax.default_backend() != "tpu"
 
     def one(pt, ln, qq):
-        return paged_attn_one_seq(pt, ln[None], qq, k_pages_l, v_pages_l,
-                                  interpret=interpret)
+        return paged_attn_one_seq(pt, ln[None], qq, k_pages_l, v_pages_l)
 
     return jax.vmap(one)(pts, seq_lens, q)
 

@@ -9,8 +9,9 @@ Per decode step and layer:
   3. the Pallas paged-attention kernel attends over the page table.
 
 Sequences are ragged (per-sequence lengths/pages) — the continuous-batching
-path the dense serve/step.py cannot express.  Pallas kernels only lower on
-real TPUs, so this path runs interpret=True here.
+path the dense serve/step.py cannot express.  Pallas kernels lower for
+real only on a TPU; elsewhere this path runs them interpreted
+(``kernels.resolve_interpret``).
 
 NOTE: this is the LEGACY reference path.  It does B·L host→device calls and
 one host sync per decoded token — kept as the numerical oracle for

@@ -70,6 +70,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -166,6 +167,12 @@ class _SlotState:
         return self.fed < self.prefill_len
 
 
+def _to_device(buf: np.ndarray) -> jax.Array:
+    """A reused staging buffer as a device array that can never alias it:
+    the transfer's host source is a private copy nothing writes again."""
+    return jnp.asarray(buf.copy())
+
+
 class Scheduler:
     def __init__(self, engine: PagedEngine, prefill_chunk: int = 8,
                  prefix_cache: Optional[PrefixCache] = None,
@@ -222,11 +229,12 @@ class Scheduler:
         # dispatched with, reconciled at the NEXT tick's sync point
         self._pending: Optional[tuple] = None
         # staging buffers, allocated once and reused every tick.  They MUST
-        # cross the jit boundary via jnp.array (copy=True): jnp.asarray is
-        # zero-copy on CPU when alignment permits, which would alias the
-        # dispatch's input to a buffer we refill next tick — with async
-        # dispatch and no intervening sync (a mid-prompt prefill tick) that
-        # is silent KV corruption.
+        # cross the jit boundary through _to_device (a private host copy):
+        # a host-to-device transfer may alias its numpy source (zero-copy
+        # on CPU, and jnp.array does not copy numpy input either), which
+        # would alias the dispatch's input to a buffer we refill next tick
+        # — with async dispatch and no intervening sync (a mid-prompt
+        # prefill tick) that is silent KV corruption.
         S = engine.max_seqs
         self._pre_toks = np.zeros((S, prefill_chunk), np.int32)
         self._pre_counts = np.zeros((S,), np.int32)
@@ -802,8 +810,8 @@ class Scheduler:
             return None
         pre, counts = staged
         with self._span("tick.prefill_launch", slots=len(pre)):
-            nxt_dev = self.engine.prefill_chunk(jnp.array(self._pre_toks),
-                                                jnp.array(self._pre_counts))
+            nxt_dev = self.engine.prefill_chunk(_to_device(self._pre_toks),
+                                                _to_device(self._pre_counts))
         self.stats["prefill_tokens"] += int(counts.sum())
         return pre, counts, nxt_dev
 
@@ -886,7 +894,7 @@ class Scheduler:
                 mask[s] = True
                 steps[s] = wants[s]     # exactly the span reserved above
             block = self.engine.decode_many(
-                jnp.array(toks), jnp.array(mask), jnp.array(steps), k)
+                _to_device(toks), _to_device(mask), _to_device(steps), k)
         self._pending = (block, dec_ids, wants)
 
     def _decode_reconcile(self) -> None:

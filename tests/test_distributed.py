@@ -23,6 +23,7 @@ def run_py(body: str) -> str:
 def test_sharded_train_step_matches_single_device():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.models import ModelConfig
         from repro.train.step import init_train_state, make_train_step
         from repro.optim.adamw import AdamWConfig
@@ -41,7 +42,7 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         s_ref, m_ref = jax.jit(step)(jax.tree.map(lambda x: x, state), batch)
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, logical_axes(mesh):
             st_sh = shardings_of(state_specs(cfg, jax.eval_shape(lambda: state), mesh), mesh)
             b_sh = shardings_of(batch_spec(cfg, jax.eval_shape(lambda: batch), mesh), mesh)
@@ -60,6 +61,7 @@ def test_sharded_train_step_matches_single_device():
 def test_moe_ep_and_decode_on_mesh():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.models.config import ModelConfig
         from repro.models.layers import _moe_local
         from repro.distributed.moe_ep import moe_ep
@@ -69,7 +71,7 @@ def test_moe_ep_and_decode_on_mesh():
                           expert_d_ff=48, vocab=64, n_experts=8, top_k=2,
                           capacity_factor=8.0, moe_groups=1,
                           param_dtype="float32", compute_dtype="float32")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         p = _init_moe(cfg, jax.random.key(1), jnp.float32)
         x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 16, 32)),
                         jnp.float32)
@@ -85,6 +87,7 @@ def test_moe_ep_and_decode_on_mesh():
 def test_elastic_checkpoint_resharding(tmp_path):
     run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.models import ModelConfig
         from repro.train.step import init_train_state
         from repro.optim.adamw import AdamWConfig
@@ -98,12 +101,12 @@ def test_elastic_checkpoint_resharding(tmp_path):
         state = init_train_state(cfg, opt, jax.random.key(3))
         shape = jax.eval_shape(lambda: state)
         # save while sharded on an 8-chip mesh
-        meshA = jax.make_mesh((2, 4), ("data", "model"))
+        meshA = make_mesh((2, 4), ("data", "model"))
         stA = jax.device_put(state, shardings_of(
             state_specs(cfg, shape, meshA), meshA))
         save_pytree(stA, r"{tmp_path}", 1)
         # restore onto a DIFFERENT (shrunk) mesh — elastic restart
-        meshB = jax.make_mesh((2, 2), ("data", "model"))
+        meshB = make_mesh((2, 2), ("data", "model"))
         shB = shardings_of(state_specs(cfg, shape, meshB), meshB)
         stB = restore_pytree(state, r"{tmp_path}", 1, shB)
         for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(stB)):
@@ -117,6 +120,7 @@ def test_elastic_checkpoint_resharding(tmp_path):
 def test_pipeline_parallel_matches_sequential():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distributed.pipeline import pipeline_apply
 
         P_st, M, mb, d = 4, 6, 8, 16
@@ -132,7 +136,7 @@ def test_pipeline_parallel_matches_sequential():
         for i in range(P_st):
             ref = jax.vmap(lambda h: stage(Ws[i], h))(ref)
 
-        mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+        mesh = make_mesh((4, 2), ("pipe", "data"))
         out = pipeline_apply(stage, Ws, x, mesh, axis="pipe")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5)
@@ -143,12 +147,13 @@ def test_pipeline_parallel_matches_sequential():
 def test_compressed_psum_under_shard_map():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from functools import partial
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64))
                         * 0.01, jnp.float32)
         f = shard_map(lambda s: compressed_psum(s[0], "pod")[None],
@@ -167,6 +172,7 @@ def test_dryrun_cell_on_small_mesh():
     version of the 512-device sweep (which runs as its own process)."""
     run_py("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import smoke_config
         from repro.train.step import make_train_step, abstract_train_state
         from repro.optim.adamw import AdamWConfig
@@ -179,7 +185,7 @@ def test_dryrun_cell_on_small_mesh():
         opt = AdamWConfig()
         st = abstract_train_state(cfg, opt)
         batch = batch_specs(cfg, 8, 64)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, logical_axes(mesh):
             in_sh = (shardings_of(state_specs(cfg, st, mesh), mesh),
                      shardings_of(batch_spec(cfg, batch, mesh), mesh))

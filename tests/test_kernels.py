@@ -134,3 +134,13 @@ def test_paged_attention_ignores_garbage_pages():
     o1 = paged_attn_one_seq(*[jnp.asarray(v) for v in (pt1, ln, q, kp, vp)])
     o2 = paged_attn_one_seq(*[jnp.asarray(v) for v in (pt2, ln, q, kp, vp)])
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
+
+
+# -- run mode ----------------------------------------------------------------
+@pytest.mark.parametrize("explicit", [None, True, False])
+def test_interpret_mode_follows_backend_unless_given(explicit):
+    """One rule for every kernel entry point: interpret off a TPU, lower
+    for real on one; an explicit choice wins."""
+    from repro.kernels import resolve_interpret
+    want = (jax.default_backend() != "tpu") if explicit is None else explicit
+    assert resolve_interpret(explicit) is want

@@ -214,3 +214,15 @@ def test_placement_is_a_block_property(archs):
     assert len(blk1.placement) == 1
     assert not (blk1.props & VBProps.SHARDED)
     eng1.alloc.free(blk1)
+
+
+@pytest.mark.parametrize("shape,axes", [((1, 1), ("data", "model")),
+                                        ((1,), ("pod",))])
+def test_meshes_are_built_with_auto_axes(shape, axes):
+    """JAX 0.9's make_mesh defaults to Explicit axes, under which the
+    vocab-sharded embedding gather raises; every mesh here is Auto."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(shape, axes)
+    assert mesh.axis_names == axes
+    assert all(t == AxisType.Auto for t in mesh.axis_types)

@@ -120,3 +120,32 @@ def test_dryrun_artifacts_complete_if_present():
         if not r.get("ok"):
             bad.append(os.path.basename(f))
     assert not bad, f"failed cells: {bad}"
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed path in the checkout — never a temp name."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/cache")
+        assert compile_cache.enable_compile_cache() == "/some/cache"
+        assert jax.config.jax_compilation_cache_dir == "/some/cache"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_serve_launcher_smoke_flag_turns_off():
+    """--smoke is the default and --no-smoke reaches the published widths
+    (the flag used to be store_true with default=True)."""
+    from repro.launch.serve import build_parser, serve_config
+    ap = build_parser()
+    assert ap.parse_args([]).smoke is True
+    args = ap.parse_args(["--no-smoke"])
+    assert args.smoke is False
+    assert serve_config("qwen3-0.6b", args.smoke).d_model == 1024
