@@ -1,0 +1,2 @@
+"""decode_step_ms.tput: see ``readers.decode_step_ms``."""
+from readers import decode_step_ms as read  # noqa: F401

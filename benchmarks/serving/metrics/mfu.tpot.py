@@ -1,0 +1,2 @@
+"""mfu.tpot: see ``readers.mfu``."""
+from readers import mfu as read  # noqa: F401
