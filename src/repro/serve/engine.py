@@ -238,7 +238,10 @@ def _token_step(cfg: ModelConfig, geom: StackGeom, max_pages: int,
     """One token for every masked slot through the *heterogeneous* stack:
     reserve → per-stage scan (each period entry branches by its static
     kind: paged / ring KV scatter + attention, or recurrent update) →
-    logits.  Pure; everything stays on device."""
+    logits.  Pure; everything stays on device.  ``jax.named_scope`` names
+    the parts a profile tells apart (``kv_write``, ``attention``, ``ffn``,
+    ``logits``); they reach only the compiled HLO's ``op_name``
+    metadata."""
     state, positions = reserve_positions(state, slot_mask,
                                          has_full=geom.has_full)
     x = params["embed"][tokens].astype(jnp.float32)[:, None, :]   # [S,1,d]
@@ -259,19 +262,23 @@ def _token_step(cfg: ModelConfig, geom: StackGeom, max_pages: int,
             qg = (q[:, :, 0].astype(jnp.float32) * scale).reshape(
                 q.shape[0], cfg.n_kv, cfg.n_heads // cfg.n_kv, cfg.head_dim)
             if kind == "full":
-                k_pages, v_pages = write_token_kv(
-                    k_pages, v_pages, li, state.page_table, positions,
-                    slot_mask, k[:, :, 0], v[:, :, 0])
-                o = attn_fn(qg, k_pages[li], v_pages[li], state.page_table,
-                            state.seq_lens, max_pages)
+                with jax.named_scope("kv_write"):
+                    k_pages, v_pages = write_token_kv(
+                        k_pages, v_pages, li, state.page_table, positions,
+                        slot_mask, k[:, :, 0], v[:, :, 0])
+                with jax.named_scope("attention"):
+                    o = attn_fn(qg, k_pages[li], v_pages[li],
+                                state.page_table, state.seq_lens, max_pages)
             else:
                 # bounded liveness exploited: translation pos mod window
                 # into the slot's static ring row; frames reuse in place
-                k_ring, v_ring = write_token_kv(
-                    k_ring, v_ring, li, ring_table, ring_pos, slot_mask,
-                    k[:, :, 0], v[:, :, 0])
-                o = attn_fn(qg, k_ring[li], v_ring[li], ring_table,
-                            ring_lens, geom.ring_pages)
+                with jax.named_scope("kv_write"):
+                    k_ring, v_ring = write_token_kv(
+                        k_ring, v_ring, li, ring_table, ring_pos, slot_mask,
+                        k[:, :, 0], v[:, :, 0])
+                with jax.named_scope("attention"):
+                    o = attn_fn(qg, k_ring[li], v_ring[li], ring_table,
+                                ring_lens, geom.ring_pages)
             o = o.reshape(o.shape[0], 1, -1).astype(x.dtype)
             x = x + o @ lp["attn"]["wo"]
         elif kind == "rglru":
@@ -292,8 +299,9 @@ def _token_step(cfg: ModelConfig, geom: StackGeom, max_pages: int,
             x = x + o
         if kind != "mamba":                              # channel mixer
             h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            y = (moe(lp["moe"], h2, cfg) if spec.moe
-                 else mlp(lp["mlp"], h2, cfg.act))
+            with jax.named_scope("ffn"):
+                y = (moe(lp["moe"], h2, cfg) if spec.moe
+                     else mlp(lp["mlp"], h2, cfg.act))
             x = x + y
         return x, (k_pages, v_pages, k_ring, v_ring, rg_h, rg_conv,
                    ssm_st, ssm_cv)
@@ -317,7 +325,9 @@ def _token_step(cfg: ModelConfig, geom: StackGeom, max_pages: int,
         state, k_pages=pools[0], v_pages=pools[1], k_ring=pools[2],
         v_ring=pools[3], rg_h=pools[4], rg_conv=pools[5],
         ssm_state=pools[6], ssm_conv=pools[7])
-    return _logits(cfg, params, x), state
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, x)
+    return logits, state
 
 
 # --------------------------------------------------------------------------

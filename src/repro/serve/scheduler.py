@@ -286,7 +286,6 @@ class Scheduler:
         n_pre = sum(1 for st in self.slots.values() if st.prefilling)
         vals = {
             "alloc.free_pages": al.free_pages,
-            "alloc.pages_used": self.engine.n_pages - 1 - al.free_pages,
             "swap.pages_used": al.swap.used_pages if al.swap else 0,
             "swap.bytes_held": al.swap.bytes_held if al.swap else 0,
             "swap.bytes_out": a_stats.get("swap_bytes_out", 0),
@@ -305,15 +304,6 @@ class Scheduler:
                 - self.prefix_cache.evictable_pages
                 if self.prefix_cache else 0),
         }
-        # per-placement occupancy (DESIGN.md §13): the pool's used pages
-        # attributed to each device of its placement set.  Sharded pools
-        # split a page's payload across all devices, so each device holds
-        # the full used count of page *slots* at 1/n the bytes; the gauge
-        # reports slot occupancy per device, still from host mirrors only.
-        placement = getattr(self.alloc, "placement", ())
-        used = self.engine.n_pages - 1 - al.free_pages
-        for dev in placement:
-            vals[f"placement.{dev}.pages_used"] = used
         for k, v in vals.items():
             self.metrics.gauge(k).set(v)
         if self.tracer is not None:
@@ -878,7 +868,8 @@ class Scheduler:
                 self.stats["decode_tick_retries"] += len(fired)
         wants = {}
         if dec_ids:
-            k, wants = self._plan_horizon(dec_ids)
+            with self._span("tick.decode_plan", slots=len(dec_ids)):
+                k, wants = self._plan_horizon(dec_ids)
             dec_ids = [s for s in dec_ids if s in self.slots and s in wants]
         if not dec_ids:
             return
@@ -933,10 +924,12 @@ class Scheduler:
     def _evict_finished(self) -> None:
         """Eviction: max_new reached, or the device emitted EOS."""
         eos = self.engine.eos_id
-        for s in [s for s, st in self.slots.items()
-                  if len(st.req.out) >= st.req.max_new
-                  or (eos >= 0 and st.req.out and st.req.out[-1] == eos)]:
-            self._evict(s)
+        with self._span("tick.evict"):
+            for s in [s for s, st in self.slots.items()
+                      if len(st.req.out) >= st.req.max_new
+                      or (eos >= 0 and st.req.out
+                          and st.req.out[-1] == eos)]:
+                self._evict(s)
 
     def step(self) -> List[Request]:
         """Admit, prefill one chunk, decode one horizon (``decode_horizon``
@@ -951,6 +944,10 @@ class Scheduler:
         Per-request outputs are bit-identical either way; only *when* a
         queued request is admitted can shift by one tick."""
         self.stats["steps"] += 1
+        with self._span("sched.step"):
+            return self._tick()
+
+    def _tick(self) -> List[Request]:
         done_before = len(self.finished)
         if self.overlap and self._pending is not None:
             self.stats["overlap_staged_ticks"] += 1
@@ -980,7 +977,8 @@ class Scheduler:
         if not self.overlap:
             self._decode_reconcile()
             self._evict_finished()
-        self._sample_gauges()
+        with self._span("tick.gauges"):
+            self._sample_gauges()
         return self.finished[done_before:]
 
     def run(self, max_steps: int = 100_000) -> List[Request]:

@@ -22,7 +22,9 @@ Three pieces, each usable alone:
   * :class:`TraceRecorder` — an event log of typed records: per-request
     lifecycle events (arrive → admit → prefill → horizon → preempt /
     swap → finish), per-tick host timeline spans (admit, stage, launch,
-    reconcile — with the sync-ready/sync-wait verdict), every VBI block
+    reconcile — with the sync-ready/sync-wait verdict — each also a
+    ``jax.profiler.TraceAnnotation``, so a profiler trace places them
+    beside the device ops), every VBI block
     op carrying its declared :class:`~repro.core.vbi.address_space.VBProps`
     (so *why* a block was placed where it was is visible in the trace),
     and per-tick gauge samples.  Exports JSONL (one event per line) and
@@ -52,6 +54,8 @@ import json
 import time
 from collections.abc import MutableMapping
 from typing import Callable, Dict, Iterable, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from ..core.vbi.address_space import VBProps
 
@@ -297,11 +301,15 @@ class TraceRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Record a complete host timeline span around a ``with`` body."""
+        """Record a complete host timeline span around a ``with`` body.
+        The body also runs inside a profiler ``TraceAnnotation`` of the
+        bare ``name``, so a ``jax.profiler`` trace shows the span on its
+        host plane, on the same clock as the device ops."""
         t0 = self.now()
         ext: Dict[str, object] = {}
         try:
-            yield ext
+            with TraceAnnotation(name):
+                yield ext
         finally:
             args.update(ext)
             self.events.append({"ts": t0, "type": "span", "name": name,

@@ -254,3 +254,112 @@ def test_trace_recorder_span_and_clock_injection():
     assert ev["type"] == "span" and ev["name"] == "tick.test"
     assert ev["ts"] == 0.0 and ev["dur"] == 0.25
     assert ev["tick"] == 3 and ev["slots"] == 2
+
+
+# --------------------------------------------------------------------------
+# the profiler bridge: spans on the device trace's clock, scopes in the HLO
+# --------------------------------------------------------------------------
+TICK_PHASES = {"tick.admit", "tick.prefill_stage", "tick.prefill_launch",
+               "tick.prefill_finish", "tick.decode_plan",
+               "tick.decode_dispatch", "tick.decode_reconcile",
+               "tick.evict", "tick.gauges"}
+
+
+def _small_sched(cfg, params, telemetry, overlap=False):
+    eng = PagedEngine(cfg, params, n_pages=17, page_size=4, max_seqs=2,
+                      max_pages_per_seq=8)
+    return Scheduler(eng, prefill_chunk=4, decode_horizon=2,
+                     overlap=overlap, telemetry=telemetry)
+
+
+def _serve_two(sched):
+    sched.add_request([3, 1, 4, 1, 5, 9], max_new=4)
+    sched.add_request([2, 7, 1], max_new=3)
+    return sched.run()
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_profiler_trace_holds_the_scheduler_spans(qwen, tmp_path, overlap):
+    """With a tracer, every span the scheduler records is also a host
+    event of a ``jax.profiler`` trace, under its bare name, and each tick
+    phase lies inside the ``sched.step`` span of its tick."""
+    from jax.profiler import ProfileData
+    cfg, params = qwen
+    _serve_two(_small_sched(cfg, params, None, overlap))   # compile first
+    telem = Telemetry(trace=True)
+    sched = _small_sched(cfg, params, telem, overlap)
+    with jax.profiler.trace(str(tmp_path)):
+        _serve_two(sched)
+    recorded = {e["name"] for e in telem.tracer.events
+                if e["type"] == "span"}
+    assert {"sched.step"} | TICK_PHASES <= recorded
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    lines = [[(e.name, e.start_ns, e.end_ns) for e in line.events]
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:CPU") for line in plane.lines]
+    seen = {n for evs in lines for n, _, _ in evs}
+    assert recorded <= seen
+    n_steps = sum(n == "sched.step" for evs in lines for n, _, _ in evs)
+    assert n_steps == sched.stats["steps"]
+    for evs in lines:
+        steps = [(s, e) for n, s, e in evs if n == "sched.step"]
+        for n, s, e in evs:
+            if n in TICK_PHASES:
+                assert any(a <= s and e <= b for a, b in steps), n
+
+
+def test_no_tracer_enters_no_trace_annotation(qwen, monkeypatch):
+    """The profiler spans exist exactly when the scheduler holds a
+    tracer: without one, not one ``TraceAnnotation`` is entered."""
+    import repro.serve.telemetry as telemetry_mod
+    entered = []
+
+    class Counting:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(telemetry_mod, "TraceAnnotation", Counting)
+    cfg, params = qwen
+    _serve_two(_small_sched(cfg, params, None))
+    assert entered == []
+    _serve_two(_small_sched(cfg, params, Telemetry(trace=True)))
+    assert "sched.step" in entered and TICK_PHASES & set(entered)
+
+
+@pytest.mark.parametrize("program", ["decode_many", "prefill"])
+def test_compiled_programs_carry_the_named_scopes(qwen, program):
+    """The token step's parts reach the compiled HLO's ``op_name``
+    metadata, where a profile reader maps device ops to them."""
+    import jax.numpy as jnp
+    cfg, params = qwen
+    eng = PagedEngine(cfg, params, n_pages=17, page_size=4, max_seqs=2,
+                      max_pages_per_seq=8)
+    i32 = jax.ShapeDtypeStruct((2,), jnp.int32)
+    if program == "decode_many":
+        fn = eng._horizon_fn(2)
+        args = (i32, jax.ShapeDtypeStruct((2,), jnp.bool_), i32)
+    else:
+        fn = eng._prefill
+        args = (jax.ShapeDtypeStruct((2, 4), jnp.int32), i32)
+    text = fn.lower(eng.params, eng.state, *args).compile().as_text()
+    for scope in ("kv_write", "attention", "ffn", "logits"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_duplicate_gauges_are_gone(recorded):
+    """``alloc.pages_used`` and ``placement.<device>.pages_used`` only
+    repeated ``n_pages - 1 - alloc.free_pages``; the sample keeps the
+    gauges the checker replays."""
+    telem = recorded[0]
+    for e in telem.tracer.events:
+        if e["type"] == "gauge":
+            assert "alloc.free_pages" in e["values"]
+            assert "swap.pages_used" in e["values"]
+            assert not any(k.endswith(".pages_used") and k != "swap.pages_used"
+                           for k in e["values"])
