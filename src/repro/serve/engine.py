@@ -191,21 +191,25 @@ def build_stack_geom(cfg: ModelConfig, page_size: int) -> StackGeom:
 # --------------------------------------------------------------------------
 # batched paged attention over the device page pool
 # --------------------------------------------------------------------------
-def batched_paged_attention(q: jax.Array, k_pages_l: jax.Array,
-                            v_pages_l: jax.Array, page_table: jax.Array,
-                            seq_lens: jax.Array, max_pages: int) -> jax.Array:
+def batched_paged_attention(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, layer: jax.Array,
+                            page_table: jax.Array, seq_lens: jax.Array,
+                            max_pages: int) -> jax.Array:
     """All slots at once, translation via the device page table.
 
-    q [S, n_kv, g, hd] (pre-scaled f32); k/v_pages_l [n_pages, ps, n_kv, hd];
+    q [S, n_kv, g, hd] (pre-scaled f32); k/v_pages [n_layers, n_pages, ps,
+    n_kv, hd], the stacked pools; ``layer`` the (traced) layer index;
     page_table [S, max_pages_per_seq]; seq_lens [S] → out [S, n_kv, g, hd].
+    One gather indexes (layer, page) together, so a slot's rows are read
+    straight out of the stacked pool and no layer's pool is ever copied.
     The ring pool uses the same contract with its static page row and
     ``seq_lens`` clipped to the window.
     """
     pts = page_table[:, :max_pages]                       # [S, P]
     S, P = pts.shape
-    ps = k_pages_l.shape[1]
-    k = k_pages_l[pts].reshape(S, P * ps, *k_pages_l.shape[2:])
-    v = v_pages_l[pts].reshape(S, P * ps, *v_pages_l.shape[2:])
+    ps = k_pages.shape[2]
+    k = k_pages[layer, pts].reshape(S, P * ps, *k_pages.shape[3:])
+    v = v_pages[layer, pts].reshape(S, P * ps, *v_pages.shape[3:])
     s = jnp.einsum("shgd,sphd->shgp", q, k.astype(q.dtype))
     mask = (jnp.arange(P * ps)[None] < seq_lens[:, None])[:, None, None, :]
     s = jnp.where(mask, s, -1e30)
@@ -215,12 +219,14 @@ def batched_paged_attention(q: jax.Array, k_pages_l: jax.Array,
     return out / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
 
 
-def _kernel_paged_attention(q, k_pages_l, v_pages_l, page_table, seq_lens,
-                            max_pages: int) -> jax.Array:
+def _kernel_paged_attention(q, k_pages, v_pages, layer, page_table,
+                            seq_lens, max_pages: int) -> jax.Array:
     """Same contract via the Pallas kernel (vmapped over slots); lowers for
     real on TPU, interpret-mode everywhere else
-    (``kernels.resolve_interpret``)."""
+    (``kernels.resolve_interpret``).  The custom call cannot read through
+    a slice, so the layer's pool is sliced here."""
     pts = page_table[:, :max_pages]
+    k_pages_l, v_pages_l = k_pages[layer], v_pages[layer]
 
     def one(pt, ln, qq):
         return paged_attn_one_seq(pt, ln[None], qq, k_pages_l, v_pages_l)
@@ -267,8 +273,8 @@ def _token_step(cfg: ModelConfig, geom: StackGeom, max_pages: int,
                         k_pages, v_pages, li, state.page_table, positions,
                         slot_mask, k[:, :, 0], v[:, :, 0])
                 with jax.named_scope("attention"):
-                    o = attn_fn(qg, k_pages[li], v_pages[li],
-                                state.page_table, state.seq_lens, max_pages)
+                    o = attn_fn(qg, k_pages, v_pages, li, state.page_table,
+                                state.seq_lens, max_pages)
             else:
                 # bounded liveness exploited: translation pos mod window
                 # into the slot's static ring row; frames reuse in place
@@ -277,7 +283,7 @@ def _token_step(cfg: ModelConfig, geom: StackGeom, max_pages: int,
                         k_ring, v_ring, li, ring_table, ring_pos, slot_mask,
                         k[:, :, 0], v[:, :, 0])
                 with jax.named_scope("attention"):
-                    o = attn_fn(qg, k_ring[li], v_ring[li], ring_table,
+                    o = attn_fn(qg, k_ring, v_ring, li, ring_table,
                                 ring_lens, geom.ring_pages)
             o = o.reshape(o.shape[0], 1, -1).astype(x.dtype)
             x = x + o @ lp["attn"]["wo"]

@@ -4,6 +4,8 @@
   * scheduler admission/eviction reuses freed pages (device free stack);
   * the decode step performs NO host transfers (jax.transfer_guard) and
     donates the KV state;
+  * attention gathers a slot's rows straight out of the stacked pool,
+    bit for bit what slicing the layer's pool and gathering from it gave;
   * preemption under pool pressure keeps results well-formed.
 """
 import dataclasses
@@ -13,10 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.vbi.kvcache import init_serve_state, release_slot
+from repro.core.vbi.kvcache import (init_serve_state, make_ring_table,
+                                    release_slot)
 from repro.launch.serve import serve_config
 from repro.models.model import init_params
-from repro.serve.engine import PagedEngine
+from repro.serve.engine import PagedEngine, batched_paged_attention
 from repro.serve.paged import PagedServer
 from repro.serve.scheduler import Scheduler
 
@@ -119,6 +122,57 @@ def test_release_slot_returns_pages_to_free_stack():
     np.testing.assert_array_equal(np.asarray(out.free_stack[4:6]), [5, 3])
     assert int(out.seq_lens[0]) == 0
     assert not bool(out.slot_active[0])
+
+
+def _slice_then_gather(q, k_pages, v_pages, layer, page_table, seq_lens,
+                       max_pages):
+    """The earlier formulation: copy out the layer's pool, then gather."""
+    k_l, v_l = k_pages[layer], v_pages[layer]
+    pts = page_table[:, :max_pages]
+    S, P = pts.shape
+    ps = k_l.shape[1]
+    k = k_l[pts].reshape(S, P * ps, *k_l.shape[2:])
+    v = v_l[pts].reshape(S, P * ps, *v_l.shape[2:])
+    s = jnp.einsum("shgd,sphd->shgp", q, k.astype(q.dtype))
+    mask = (jnp.arange(P * ps)[None] < seq_lens[:, None])[:, None, None, :]
+    s = jnp.where(mask, s, -1e30)
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    p = jnp.where(mask, p, 0.0)
+    out = jnp.einsum("shgp,sphd->shgd", p, v.astype(q.dtype))
+    return out / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+
+
+@pytest.mark.parametrize("pool", ("full", "ring"))
+def test_attention_gathers_through_the_stacked_pool(pool):
+    """One (layer, page) gather over the stacked pool reads, bit for bit,
+    what slicing out the layer's pool and then gathering read: slots of
+    mixed lengths, an empty slot, rows that end in the null page 0."""
+    L, ps, n_kv, g, hd, S = 3, 4, 2, 2, 8, 4
+    if pool == "full":
+        n_pages, max_pages = 13, 3
+        table = np.zeros((S, 4), np.int32)
+        table[0, :3] = (5, 2, 9)
+        table[1, :1] = 7                         # then the null page
+        table[3, :3] = (1, 12, 3)
+        lens = np.asarray([11, 3, 0, 12], np.int32)
+    else:
+        max_pages = 2                            # ring pages per slot
+        table = make_ring_table(S, max_pages)
+        n_pages = 1 + S * max_pages
+        lens = np.minimum([11, 3, 0, 6], max_pages * ps).astype(np.int32)
+    rng = np.random.default_rng(7)
+    k_pages, v_pages = (jnp.asarray(rng.standard_normal(
+        (L, n_pages, ps, n_kv, hd)), jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((S, n_kv, g, hd)), jnp.float32)
+    table, lens = jnp.asarray(table), jnp.asarray(lens)
+    new = jax.jit(batched_paged_attention, static_argnums=6)
+    old = jax.jit(_slice_then_gather, static_argnums=6)
+    for li in range(L):
+        args = (q, k_pages, v_pages, jnp.int32(li), table, lens, max_pages)
+        out = np.asarray(new(*args))
+        np.testing.assert_array_equal(out, np.asarray(old(*args)))
+        assert not out[2].any()                  # the empty slot reads 0
+        assert np.isfinite(out).all()
 
 
 def test_decode_step_no_host_transfers(setup):

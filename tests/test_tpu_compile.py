@@ -10,9 +10,12 @@ worker that runs this file loads the TPU library.
   head_dim 128, 2 query heads per KV head), pages of 8 and 16;
 * qwen3-0.6b's decode step, prefill chunk and K=8 fused decode horizon,
   gather path, from ``jax.eval_shape`` parameters;
+* the same three programs copy no layer's KV pool: attention gathers a
+  slot's rows straight out of the stacked pool;
 * the kernel-path decode step, which must hold the kernel as a
   ``tpu_custom_call`` (the interpret switch is steered in the test).
 """
+import re
 from functools import partial
 
 import jax
@@ -57,14 +60,26 @@ def _on(sharding, tree):
         tree)
 
 
-def _engine(one_chip, attn_impl="gather"):
+def _engine(one_chip, attn_impl="gather", n_pages=33):
     """qwen3-0.6b at its published widths over abstract parameters; with
     no mesh the engine never touches them."""
     cfg = serve_config("qwen3-0.6b", smoke=False)
     params = jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-    eng = PagedEngine(cfg, params, page_size=8, max_seqs=4, n_pages=33,
+    eng = PagedEngine(cfg, params, page_size=8, max_seqs=4, n_pages=n_pages,
                       attn_impl=attn_impl)
     return eng, _on(one_chip, params), _on(one_chip, eng.state)
+
+
+def _lower(one_chip, eng, params, state, program):
+    S = eng.max_seqs
+    i32 = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+    if program == "decode":
+        return eng._decode.lower(params, state, i32, mask)
+    if program == "prefill":
+        chunk = jax.ShapeDtypeStruct((S, 8), jnp.int32, sharding=one_chip)
+        return eng._prefill.lower(params, state, chunk, i32)
+    return eng._horizon_fn(8).lower(params, state, i32, mask, i32)
 
 
 @pytest.mark.parametrize("page_size", (8, 16))
@@ -85,19 +100,24 @@ def test_paged_attention_kernel_compiles(one_chip, page_size):
 @pytest.mark.parametrize("program", ("decode", "prefill", "decode_many"))
 def test_serve_programs_compile_at_published_widths(one_chip, program):
     eng, params, state = _engine(one_chip)
-    S = eng.max_seqs
-    i32 = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
-    if program == "decode":
-        lowered = eng._decode.lower(params, state, i32, mask)
-    elif program == "prefill":
-        chunk = jax.ShapeDtypeStruct((S, 8), jnp.int32, sharding=one_chip)
-        lowered = eng._prefill.lower(params, state, chunk, i32)
-    else:
-        lowered = eng._horizon_fn(8).lower(params, state, i32, mask, i32)
-    mem = lowered.compile().memory_analysis()
+    mem = _lower(one_chip, eng, params, state,
+                 program).compile().memory_analysis()
     # the f32 weights are the arguments: ~2.4 GB at published widths
     assert mem.argument_size_in_bytes > 2e9
+
+
+@pytest.mark.parametrize("program", ("decode", "prefill", "decode_many"))
+def test_serve_programs_copy_no_layer_pool(one_chip, program):
+    # 37 pages is no other dimension of the program, so one layer's pool
+    # [37, page_size, n_kv, head_dim] shows in the compiled text, in any
+    # dtype, only if something copies it out of the stacked pool
+    eng, params, state = _engine(one_chip, n_pages=37)
+    assert state.k_pages.shape == (28, 37, 8, 8, 128)
+    text = _lower(one_chip, eng, params, state, program).compile().as_text()
+    assert "[28,37,8,8,128]" in text
+    copies = [ln for ln in text.splitlines()
+              if re.search(r"[a-z]\d*\[37,8,8,128\]", ln)]
+    assert not copies, copies[:3]
 
 
 def test_kernel_decode_step_holds_the_kernel(one_chip, monkeypatch):
